@@ -56,6 +56,7 @@ from repro.core import forest as forest_mod
 from repro.core import nsga2, quant
 from repro.datasets import load_dataset
 from repro import search
+from repro.search.problem import area_mm2
 
 ARTIFACT = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                         "BENCH_search.json")
@@ -104,10 +105,7 @@ def _looped_forest_fitness(forest, problem):
         [jnp.asarray(p.threshold) for p in forest.ptrees])
     exact_acc = problem.exact_accuracy
     exact_area = problem.exact_area_mm2
-    lut, offsets = problem.area_lut, problem.lut_offsets
-    overhead = problem.overhead_mm2
-    vote_exact = jnp.float32(problem.vote_mm2_exact)
-    vote_approx = jnp.float32(problem.vote_mm2_approx)
+    lut, offsets = problem.area_lut_units, problem.lut_offsets
     n_classes = forest.n_classes
 
     @jax.jit
@@ -136,8 +134,8 @@ def _looped_forest_fitness(forest, problem):
                                  jnp.float32(jnp.inf))
             pred = jnp.argmax(jnp.minimum(votes, vote_cap), axis=1)
             acc = jnp.mean((pred == y).astype(jnp.float32))
-            a = lut[offsets[bits_eff] + t_eff].sum() + overhead
-            a = a + jnp.where(jnp.isfinite(vote_cap), vote_approx, vote_exact)
+            a = area_mm2(problem, lut[offsets[bits_eff] + t_eff].sum(),
+                         vote_cap)
             return jnp.stack([exact_acc - acc, a / exact_area])
         return jax.vmap(one)(pop)
 
@@ -237,13 +235,10 @@ def _seed_reference_fitness(problem):
             t_sub2 = quant.substitute(
                 quant.threshold_to_int(problem.threshold, bits2),
                 margin2, bits2)
-            area = problem.area_lut[
+            area = area_mm2(problem, problem.area_lut_units[
                 problem.lut_offsets[bits2 - trunc2]
-                + jnp.right_shift(t_sub2, trunc2)].sum()
-            area = area + problem.overhead_mm2
-            area = area + jnp.where(vote2 > 0,
-                                    jnp.float32(problem.vote_mm2_approx),
-                                    jnp.float32(problem.vote_mm2_exact))
+                + jnp.right_shift(t_sub2, trunc2)].sum(),
+                jnp.where(vote2 > 0, 1.0, jnp.inf))
             return jnp.stack([problem.exact_accuracy - acc,
                               area / problem.exact_area_mm2])
         return jax.vmap(one)(pop)
@@ -448,12 +443,8 @@ def _scores_kernel_fitness(problem):
         # historical double decode for the area term
         scale2, t_sub2, bits2, vote_cap2 = kops.decode_population_full(
             threshold, pop)
-        areas = problem.area_lut[
-            problem.lut_offsets[bits2] + t_sub2].sum(axis=1)
-        areas = areas + problem.overhead_mm2
-        areas = areas + jnp.where(jnp.isfinite(vote_cap2),
-                                  jnp.float32(problem.vote_mm2_approx),
-                                  jnp.float32(problem.vote_mm2_exact))
+        areas = area_mm2(problem, problem.area_lut_units[
+            problem.lut_offsets[bits2] + t_sub2].sum(axis=1), vote_cap2)
         return jnp.stack(
             [problem.exact_accuracy - acc, areas / problem.exact_area_mm2],
             axis=1,

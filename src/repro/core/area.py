@@ -172,10 +172,6 @@ def vote_adder_units(n_trees: int, n_classes: int, approx: bool) -> int:
     return iunits
 
 
-def vote_adder_area_mm2(n_trees: int, n_classes: int, approx: bool) -> float:
-    return vote_adder_units(n_trees, n_classes, approx) * AREA_QUANTUM_MM2
-
-
 # --- printed-MLP MAC / activation cells (DESIGN.md §15) ---------------------
 # A MAC term is lowered as shifted-copy rows through ripple full adders (the
 # §10 `full_add` cell: 2 XOR2 + 2 AND2 + 1 OR2); a negative weight costs one

@@ -41,7 +41,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import nsga2
@@ -52,11 +51,11 @@ def sharded_fitness(fitness_fn, mesh: Mesh, axis: str = "data"):
     pspec = P(axis)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(pspec,),
         out_specs=pspec,
-        check_rep=False,
+        check_vma=False,
     )
     def _eval(genes):
         return fitness_fn(genes)
@@ -108,8 +107,8 @@ def sharded_non_dominated_sort(objs, mesh: Mesh, axis: str = "pop"):
     sort."""
     _check_divisible(objs.shape[0], mesh, axis, "population")
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(axis),), out_specs=P(axis),
+             check_vma=False)
     def _sort(objs_local):
         full = jax.lax.all_gather(objs_local, axis, tiled=True)
         ranks = _hierarchical_ranks(objs_local, full, axis)
@@ -131,8 +130,8 @@ def sharded_crowding_distance(objs, rank, mesh: Mesh, axis: str = "pop"):
     arithmetic, returning its slab of the identical result."""
     _check_divisible(objs.shape[0], mesh, axis, "population")
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(axis), P(axis)),
-             out_specs=P(axis), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(axis), P(axis)),
+             out_specs=P(axis), check_vma=False)
     def _crowd(objs_local, rank_local):
         full = jax.lax.all_gather(objs_local, axis, tiled=True)
         rank_full = jax.lax.all_gather(rank_local, axis, tiled=True)
@@ -212,8 +211,8 @@ def _make_sharded_gen(fitness_fn, mesh: Mesh, cfg: nsga2.NSGA2Config,
 
     specs = _specs.search_state_specs(axis)
 
-    @partial(shard_map, mesh=mesh, in_specs=(specs,), out_specs=specs,
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(specs,), out_specs=specs,
+             check_vma=False)
     def _gen(state: nsga2.NSGA2State) -> nsga2.NSGA2State:
         return _sharded_gen_body(state, fitness_fn, cfg, axis)
 
@@ -270,8 +269,8 @@ def make_sharded_batched_chunk(fitness_from_ctx, mesh: Mesh,
     def chunk(states: nsga2.NSGA2State, ctxs) -> nsga2.NSGA2State:
         ctx_specs = jax.tree.map(lambda _: P(bucket_axis), ctxs)
 
-        @partial(shard_map, mesh=mesh, in_specs=(specs, ctx_specs),
-                 out_specs=specs, check_rep=False)
+        @partial(jax.shard_map, mesh=mesh, in_specs=(specs, ctx_specs),
+                 out_specs=specs, check_vma=False)
         def _chunk(states, ctxs):
             def one(state, ctx):
                 fit = lambda pop: fitness_from_ctx(ctx, pop)
@@ -359,11 +358,11 @@ def _make_round(fitness_fn, mesh: Mesh, cfg: IslandConfig, axis: str = "data"):
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(state_specs,),
         out_specs=state_specs,
-        check_rep=False,
+        check_vma=False,
     )
     def _round(state: nsga2.NSGA2State) -> nsga2.NSGA2State:
         local = nsga2.NSGA2State(

@@ -20,9 +20,9 @@ hardware) with constant propagation (a ``t' = 2^p - 1`` comparator folds to
 constant false and its dead path logic vanishes). From the finished
 `Circuit`:
 
-  - `simulate(circuit, x8)` evaluates the whole test set in one vectorized,
-    `lax.scan`-free jnp pass (gates grouped by logic level, one masked
-    gather/op per level) — the hardware oracle `core.rtl` emission is
+  - `simulate(circuit, x8)` evaluates the whole test set in one vectorized
+    numpy pass on the host (gates grouped by logic level, one gather/op per
+    level) — the hardware oracle `core.rtl` emission is
     verified against;
   - `gate_counts(circuit)` / `netlist_area_mm2(circuit)` give the
     synthesized-netlist "actual" area the GA's additive-LUT estimate is
@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import area as area_mod
@@ -577,31 +576,33 @@ def levelize(circuit: Circuit) -> np.ndarray:
     return level
 
 
-def simulate(circuit: Circuit, x8) -> jnp.ndarray:
-    """(B,) predicted class over (B, F) int master codes.
+def simulate(circuit: Circuit, x8) -> np.ndarray:
+    """(B,) int32 predicted class over (B, F) int master codes.
 
-    One vectorized pass, no `lax.scan`: gates are grouped by logic level
-    (operands always precede gates, so one linear pass levelizes), and each
-    level is a single masked gather + boolean op over all its gates at once.
-    Bit-exact against `search.problem.predict_votes` by construction —
-    asserted per pareto point by the engine's `--verify-rtl` path.
+    One vectorized host pass in numpy, no `lax.scan`: gates are grouped by
+    logic level (operands always precede gates, so one linear pass
+    levelizes), and each level is a single gather + boolean op over all its
+    gates at once. Numpy, not jnp: every level has its own gather shape, so
+    op-by-op jnp would compile (and, on an accelerator, dispatch) per level
+    and per batch size. Bit-exact against `search.problem.predict_votes` by
+    construction — asserted per pareto point by the engine's `--verify-rtl`
+    path.
     """
     op, a, b = circuit.op, circuit.a, circuit.b
     g = circuit.n_gates
     logic = op >= NOT
     level = levelize(circuit)
 
-    x8 = jnp.asarray(x8, jnp.int32)
+    x8 = np.asarray(x8, np.int32)
     n_b = x8.shape[0]
-    vals = jnp.zeros((n_b, g), jnp.bool_)
+    vals = np.zeros((n_b, g), bool)
 
     base = np.flatnonzero(level == 0)
     feat = np.maximum(a[base], 0)
     bit = np.maximum(b[base], 0)
-    in_vals = ((x8[:, feat] >> bit[None, :]) & 1).astype(jnp.bool_)
+    in_vals = ((x8[:, feat] >> bit[None, :]) & 1).astype(bool)
     base_ops = op[base][None, :]
-    base_vals = jnp.where(base_ops == INPUT, in_vals, base_ops == CONST1)
-    vals = vals.at[:, base].set(base_vals)
+    vals[:, base] = np.where(base_ops == INPUT, in_vals, base_ops == CONST1)
 
     for lvl in range(1, int(level.max()) + 1 if logic.any() else 1):
         idx = np.flatnonzero(level == lvl)
@@ -610,15 +611,14 @@ def simulate(circuit: Circuit, x8) -> jnp.ndarray:
         av = vals[:, a[idx]]
         bv = vals[:, np.maximum(b[idx], 0)]
         ops = op[idx][None, :]
-        out = jnp.where(
+        vals[:, idx] = np.where(
             ops == NOT, ~av,
-            jnp.where(ops == AND, av & bv,
-                      jnp.where(ops == OR, av | bv, av ^ bv)))
-        vals = vals.at[:, idx].set(out)
+            np.where(ops == AND, av & bv,
+                     np.where(ops == OR, av | bv, av ^ bv)))
 
-    cls = jnp.zeros((n_b,), jnp.int32)
+    cls = np.zeros((n_b,), np.int32)
     for i, w in enumerate(circuit.out_bits):
-        cls = cls | (vals[:, w].astype(jnp.int32) << i)
+        cls |= vals[:, w].astype(np.int32) << i
     return cls
 
 

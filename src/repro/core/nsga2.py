@@ -64,10 +64,8 @@ def _dispatch_domination(objs: jnp.ndarray,
     compiled program."""
     if (objs.shape[0] >= DOMINATION_KERNEL_MIN_POP
             and _kernel_domination_available()):
-        try:
-            from repro.kernels import ops as _kops
-        except ImportError:  # kernels package unavailable: oracle path
-            return domination_matrix(objs, against)
+        from repro.kernels import ops as _kops
+
         if against is None:
             return _kops.domination_matrix_bool(objs)
         return _kops.domination_block_bool(objs, against)

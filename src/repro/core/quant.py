@@ -18,6 +18,7 @@ index the area LUT are the same code scaled by 2^-p — exactly the paper's
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -31,6 +32,18 @@ MARGIN = 5  # paper §IV: threshold substitution margin m in [-5, +5]
 # p - k compared against t' >> k.
 MAX_TRUNC = 2
 VOTE_ADDER_MODES = ("exact", "approx")
+
+
+def shift_scale(shift):
+    """Exact f32 ``2^-shift`` for integer ``shift`` in [0, 126].
+
+    Built from the exponent bits: the kernels' ``floor(x8 * scale)`` equals
+    ``x8 >> shift`` only for the exact power of two, and ``jnp.exp2``
+    lowers to ``exp(x * ln 2)``, exact at these points only as far as the
+    backend's ``exp`` happens to be.
+    """
+    e = 127 - jnp.asarray(shift, jnp.int32)
+    return jax.lax.bitcast_convert_type(jnp.left_shift(e, 23), jnp.float32)
 
 
 def threshold_to_int(threshold, bits):

@@ -46,6 +46,42 @@ from jax.experimental.pallas import tpu as pltpu
 # the output block is a native (block_p, 128) f32 tile; callers read lane 0.
 LANES = 128
 
+# Scoped VMEM the TPU compiler grants one kernel by default (16 MiB on v5e).
+# A leaf tile derived for ``block_l=None`` keeps the estimated footprint
+# under it; past it the compile fails with RESOURCE_EXHAUSTED.
+VMEM_BUDGET_BYTES = 16 * 2**20
+
+
+def pick_block_l(l: int, footprint) -> int:
+    """Largest 128-multiple dividing the padded leaf axis ``l`` whose
+    ``footprint(block_l)`` (bytes) fits `VMEM_BUDGET_BYTES`.
+
+    One lane tile (128) is the floor: at that width the compiler does not
+    keep the kernel's (rows, N) comparator slabs resident, and every real
+    problem compiles there (tests/test_tpu_compile.py holds HAR forest[4],
+    N = L = 1,920, to it).
+    """
+    for block_l in range(l, 128, -128):
+        if l % block_l == 0 and footprint(block_l) <= VMEM_BUDGET_BYTES:
+            return block_l
+    return 128
+
+
+def vmem_bytes(n: int, block_l: int, c: int, block_b: int,
+               block_p: int) -> int:
+    """Estimated VMEM bytes of one `fitness_errors` grid cell.
+
+    Double-buffered operand/output blocks (a 1-row block occupies an
+    8-sublane tile), the vote scratch, and the f32 (block_p, block_b, .)
+    intermediates: four over N (scaled codes, their floor, the compare and
+    its cast) and two over ``block_l`` (path scores, leaf hits). Checked
+    against the v5e compiler's own sizes, which it never undercounts.
+    """
+    blocks = (block_b * n + 2 * block_p * n + n * block_l + 8 * block_l
+              + block_l * c + 8 * block_b + 2 * block_p * LANES)
+    slabs = block_p * block_b * (4 * n + 2 * block_l + 2 * c)
+    return 4 * (2 * blocks + slabs)
+
 
 def _kernel(xsel_ref, scale_ref, thr_ref, path_ref, target_ref, cls1h_ref,
             y_ref, vcap_ref, out_ref, votes_ref):
@@ -96,7 +132,8 @@ def _kernel(xsel_ref, scale_ref, thr_ref, path_ref, target_ref, cls1h_ref,
         v = jnp.minimum(v, vcap_ref[...][:, :1][:, :, None])
         n_cls = v.shape[-1]
         vmax = jnp.max(v, axis=-1, keepdims=True)
-        cls = jax.lax.broadcasted_iota(jnp.float32, v.shape, 2)
+        # Mosaic builds integer iotas only; class ids < 2^24 are exact in f32
+        cls = jax.lax.broadcasted_iota(jnp.int32, v.shape, 2).astype(jnp.float32)
         # first-max argmax as iota + masked min (jnp.argmax tie semantics)
         pred = jnp.min(jnp.where(v == vmax, cls, jnp.float32(n_cls)), axis=-1)
         correct = (pred == y_ref[...]).astype(jnp.float32)  # (bp, bb)
@@ -128,13 +165,15 @@ def fitness_errors(
     correctly (padded rows carry label -1 and never match); errors are
     ``n_valid - out[:, 0]``. ``block_p`` tiles the population axis,
     ``block_l`` the (concatenated) leaf axis — both must divide the padded
-    extents.
+    extents. ``block_l=None`` derives the leaf tile from the padded shapes
+    (`pick_block_l` over `vmem_bytes`): the whole axis where it fits.
     """
     n_pop = scale.shape[0]
     b, n = x_sel.shape
     l, c = cls1h.shape
     if block_l is None:
-        block_l = l
+        block_l = pick_block_l(
+            l, lambda bl: vmem_bytes(n, bl, c, block_b, block_p))
     if n_pop % block_p != 0:
         raise ValueError(f"block_p={block_p} must divide padded P={n_pop}")
     if b % block_b != 0:
@@ -158,7 +197,7 @@ def fitness_errors(
         out_specs=pl.BlockSpec((block_p, LANES), lambda p, i, j: (p, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pop, LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_p, block_b, c), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -118,7 +118,7 @@ def decode_population_full(threshold, genes):
     t_sub = quant.substitute(t_int, margin, bits)
     bits_eff = bits - trunc
     t_eff = jnp.right_shift(t_sub, trunc)
-    scale = jnp.exp2(-(8 - bits_eff).astype(jnp.float32))
+    scale = quant.shift_scale(quant.MASTER_BITS - bits_eff)
     vote_cap = jnp.where(vote > 0, jnp.float32(1.0), jnp.float32(jnp.inf))
     return scale, t_eff, bits_eff, vote_cap
 
@@ -202,7 +202,7 @@ def prepare_design(bits, t_int, trunc=None, vote_adder: str = "exact"):
         k = jnp.asarray(trunc, jnp.int32)
         bits = bits - k
         t_int = jnp.right_shift(t_int, k)
-    scale = jnp.exp2(-(quant.MASTER_BITS - bits).astype(jnp.float32))[None, :]
+    scale = quant.shift_scale(quant.MASTER_BITS - bits)[None, :]
     thr = t_int.astype(jnp.float32)[None, :]
     cap = jnp.full((1,), 1.0 if vote_adder == "approx" else jnp.inf,
                    jnp.float32)

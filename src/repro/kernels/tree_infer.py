@@ -28,7 +28,10 @@ leaves, so L can outgrow a single VMEM-resident block). Grid =
 (shift_scale, threshold) vector is a [1, N] VMEM tile indexed by the
 population coordinate; the leaf axis is the innermost (sequential) grid
 dimension so partial vote matmuls accumulate into the same revisited output
-block.
+block. The (P, N) population operands travel as (P, 1, N) with the leading
+axis squeezed out of the block: Mosaic tiles the last two block dimensions
+by (8, 128) unless they span the whole array, so a (1, N) row block of a
+(P, N) array compiles only for P = 1.
 
 All integer quantities are exact in f32 (values < 2^24) and vote accumulation
 adds small exact integers, so MXU execution is bit-exact vs the integer
@@ -42,6 +45,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.fitness import pick_block_l
+
+
+def vmem_bytes(f: int, n: int, block_l: int, c: int, block_b: int) -> int:
+    """Estimated VMEM bytes of one `tree_infer_scores` grid cell:
+    double-buffered blocks (a 1-row block occupies an 8-sublane tile) and
+    the f32 intermediates, three (block_b, N) for the gather, scaled codes
+    and compare, two (block_b, block_l) for path scores and leaf hits."""
+    blocks = (block_b * f + f * n + 2 * 8 * n + n * block_l + 8 * block_l
+              + block_l * c + block_b * c)
+    slabs = block_b * (3 * n + 2 * block_l + c)
+    return 4 * (2 * blocks + slabs)
 
 
 def _kernel(x_ref, sel_ref, scale_ref, thr_ref, path_ref, target_ref,
@@ -93,33 +109,38 @@ def tree_infer_scores(
 ):
     """Returns per-class vote counts (P, B, C); argmax over C = prediction.
 
-    ``block_l`` tiles the leaf axis (must divide L); ``None`` keeps the whole
-    (padded) leaf axis resident — the single-tree fast path.
+    ``block_l`` tiles the leaf axis (must divide L); ``None`` derives it
+    from the padded shapes under the VMEM budget (`fitness.pick_block_l`),
+    keeping the whole leaf axis resident where it fits.
     """
     n_pop = scale.shape[0]
     b, f = x8f.shape
     n = sel.shape[1]
     l, c = cls1h.shape
     if block_l is None:
-        block_l = l
+        block_l = pick_block_l(
+            l, lambda bl: vmem_bytes(f, n, bl, c, block_b))
     if l % block_l != 0:
         raise ValueError(f"block_l={block_l} must divide padded L={l}")
     grid = (n_pop, b // block_b, l // block_l)
+    # one (1, N) row per chromosome: see the module docstring
+    scale = scale.reshape(n_pop, 1, n)
+    thr = thr.reshape(n_pop, 1, n)
     return pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, f), lambda p, i, j: (i, 0)),
             pl.BlockSpec((f, n), lambda p, i, j: (0, 0)),
-            pl.BlockSpec((1, n), lambda p, i, j: (p, 0)),
-            pl.BlockSpec((1, n), lambda p, i, j: (p, 0)),
+            pl.BlockSpec((None, 1, n), lambda p, i, j: (p, 0, 0)),
+            pl.BlockSpec((None, 1, n), lambda p, i, j: (p, 0, 0)),
             pl.BlockSpec((n, block_l), lambda p, i, j: (0, j)),
             pl.BlockSpec((1, block_l), lambda p, i, j: (0, j)),
             pl.BlockSpec((block_l, c), lambda p, i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_b, c), lambda p, i, j: (p, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pop, b, c), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

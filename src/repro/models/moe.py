@@ -32,7 +32,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import activation, is_glu, normal_init
@@ -261,11 +260,11 @@ def _moe_ep(params, cfg, x, rules):
                 y = lax.psum(y, rules.model)
         return y, aux
 
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_in_spec, w_spec),
         out_specs=(x_out_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     p_used = {k: params[k] for k in w_spec.keys()}
     return wrapped(x, p_used)

@@ -15,7 +15,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -57,8 +56,8 @@ def make_crosspod_mean(mesh, axis: str = "pod"):
     def spec_for(g):
         return P()  # replicated entering the wrapper; shard_map splits axis
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(),), out_specs=P(),
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(),), out_specs=P(),
+             check_vma=False)
     def _mean(g):
         return compressed_psum(g, axis)
 

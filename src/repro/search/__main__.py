@@ -42,6 +42,7 @@ import numpy as np
 from repro.core import area
 from repro.datasets import DATASET_SPECS, load_dataset
 from repro import search
+from repro.runtime import compile_cache
 
 
 def _load_artifact_or_exit(path: str):
@@ -97,8 +98,10 @@ def sweep_main(argv=None) -> None:
                          "bucket axis x N-way population axis, 'N'/'auto' = "
                          "population axis only; default: single device")
     ap.add_argument("--compilation-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory: "
-                         "re-runs skip recompiling every bucket shape")
+                    help="persistent XLA compilation cache directory "
+                         "(default: $JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache): re-runs skip recompiling "
+                         "every bucket shape")
     ap.add_argument("--emit-rtl", action="store_true",
                     help="write every pareto point's Verilog under "
                          "OUT/<dataset>/rtl/")
@@ -124,9 +127,7 @@ def sweep_main(argv=None) -> None:
     if unknown:
         ap.error(f"unknown datasets: {unknown}; options: "
                  f"{sorted(DATASET_SPECS)}")
-    if args.compilation_cache:
-        from repro.runtime import compile_cache
-        compile_cache.enable(args.compilation_cache)
+    compile_cache.configure(args.compilation_cache)
 
     kind = "tree" if args.trees <= 1 else f"forest[{args.trees}]"
     extra = (f" + {len(mlp_names)} printed-MLP datasets" if mlp_names else "")
@@ -265,11 +266,11 @@ def serve_main(argv=None) -> None:
                     help="simulate the served design's gate-level netlist "
                          "over every served batch and assert bit-exactness")
     ap.add_argument("--compilation-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory")
+                    help="persistent XLA compilation cache directory "
+                         "(default: $JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache)")
     args = ap.parse_args(argv)
-    if args.compilation_cache:
-        from repro.runtime import compile_cache
-        compile_cache.enable(args.compilation_cache)
+    compile_cache.configure(args.compilation_cache)
 
     artifact = _load_artifact_or_exit(args.pareto)
     point = args.point if args.point == "best" else int(args.point)
@@ -385,6 +386,7 @@ def faults_main(argv=None) -> None:
     ap.add_argument("--out", default=None,
                     help="fault_report.json path (default: next to --pareto)")
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
     artifact = _load_artifact_or_exit(args.pareto)
     dataset = args.dataset or artifact.dataset
@@ -455,7 +457,9 @@ def main(argv=None) -> None:
                          "shards the population axis over N / all devices "
                          "(islands: the ring size); default: single device")
     ap.add_argument("--compilation-cache", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory")
+                    help="persistent XLA compilation cache directory "
+                         "(default: $JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache)")
     ap.add_argument("--block-p", type=int, default=8,
                     help="kernel backend: chromosomes per fused-fitness grid "
                          "cell (population-axis tile, DESIGN.md §12)")
@@ -486,9 +490,7 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if (args.emit_rtl or args.verify_rtl) and not args.out:
         ap.error("--emit-rtl/--verify-rtl require --out")
-    if args.compilation_cache:
-        from repro.runtime import compile_cache
-        compile_cache.enable(args.compilation_cache)
+    compile_cache.configure(args.compilation_cache)
 
     from repro.families import get_family
 

@@ -27,10 +27,11 @@ with each other:
               `repro.search.engine.run_search`, and shares the engine's
               chunked-scan checkpoint/resume machinery (DESIGN.md §9).
 
-The accuracy term of `reference` and `kernel` agree bit-exactly: every
-integer quantity is exact in f32 (< 2^24), the kernel's on-chip reductions
-add small exact integers, and both divide the same exact correct count by
-the same sample count (see `repro.kernels.fitness`).
+`reference` and `kernel` agree bit-exactly: every integer quantity is
+exact in f32 (< 2^24), the kernel's on-chip reductions add small exact
+integers (see `repro.kernels.fitness`), the area term sums integer LUT
+quanta, exact in any order, and both end in `problem.objective_pair` on
+the same exact counts.
 """
 from __future__ import annotations
 
@@ -39,7 +40,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.search.problem import SearchProblem, objectives
+from repro.search.problem import SearchProblem, objective_pair, objectives
 
 BACKENDS = ("reference", "kernel", "islands")
 
@@ -89,16 +90,9 @@ def make_kernel_fitness(problem: SearchProblem, *, block_p: int = 8,
             fit_operands, scale, t_sub.astype(jnp.float32), vote_cap,
             block_p=block_p, block_b=block_b, block_l=block_l,
             interpret=interpret)
-        acc = (n_samples - errors) / n_samples
-        areas = problem.area_lut[problem.lut_offsets[bits] + t_sub].sum(axis=1)
-        areas = areas + problem.overhead_mm2
-        areas = areas + jnp.where(jnp.isfinite(vote_cap),
-                                  jnp.float32(problem.vote_mm2_approx),
-                                  jnp.float32(problem.vote_mm2_exact))
-        return jnp.stack(
-            [problem.exact_accuracy - acc, areas / problem.exact_area_mm2],
-            axis=1,
-        )
+        units = problem.area_lut_units[problem.lut_offsets[bits] + t_sub]
+        return jnp.stack(objective_pair(problem, n_samples - errors,
+                                        units.sum(axis=1), vote_cap), axis=1)
 
     return fitness
 

@@ -56,7 +56,8 @@ class SearchProblem:
                               #   chromosome-invariant feature gather,
                               #   computed once per problem (DESIGN.md §12)
     y: jnp.ndarray            # (B,) int32
-    area_lut: jnp.ndarray     # flat LUT (mm^2)
+    area_lut_units: jnp.ndarray  # flat LUT, integer AREA_QUANTUM_MM2 quanta
+                                 #   stored as f32 (exact, order-free sums)
     lut_offsets: jnp.ndarray  # (MAX_BITS+1,) int32
     overhead_mm2: float
     exact_area_mm2: float
@@ -66,8 +67,8 @@ class SearchProblem:
     n_trees: int
     tree_comparators: tuple   # per-tree comparator counts (static)
     tree_leaves: tuple        # per-tree leaf counts (static)
-    vote_mm2_exact: float = 0.0   # vote-stage area per adder mode — priced
-    vote_mm2_approx: float = 0.0  # from the netlist harness (DESIGN.md §16)
+    vote_units_exact: int = 0     # vote-stage area quanta per adder mode —
+    vote_units_approx: int = 0    # priced from the netlist harness (§16)
 
     @property
     def n_comparators(self) -> int:
@@ -93,10 +94,10 @@ jax.tree_util.register_pytree_node(
     SearchProblem,
     lambda p: (
         (p.feature, p.threshold, p.path, p.path_len, p.n_neg, p.leaf_class,
-         p.leaf_tree, p.x8, p.x_sel, p.y, p.area_lut, p.lut_offsets),
+         p.leaf_tree, p.x8, p.x_sel, p.y, p.area_lut_units, p.lut_offsets),
         (p.overhead_mm2, p.exact_area_mm2, p.exact_accuracy, p.n_classes,
          p.n_features, p.n_trees, p.tree_comparators, p.tree_leaves,
-         p.vote_mm2_exact, p.vote_mm2_approx),
+         p.vote_units_exact, p.vote_units_approx),
     ),
     lambda aux, children: SearchProblem(*children, *aux),
 )
@@ -124,11 +125,32 @@ def decode_chromosome(problem: SearchProblem, genes):
     return bits - trunc, jnp.right_shift(t_sub, trunc), vote_cap
 
 
-def vote_area_mm2(problem: SearchProblem, vote_cap):
-    """Vote-stage area term selected by the decoded cap (0 when K = 1)."""
-    return jnp.where(jnp.isfinite(vote_cap),
-                     jnp.float32(problem.vote_mm2_approx),
-                     jnp.float32(problem.vote_mm2_exact))
+def area_mm2(problem: SearchProblem, units, vote_cap):
+    """Design area from its summed comparator LUT quanta ``units`` plus the
+    vote-adder cell the decoded cap selects (0 when K = 1).
+
+    Every term before the final scaling is an integer number of quanta, so
+    the comparator sum is exact in f32 under any reduction order or batch
+    shape, and the reference and kernel backends agree on it bit for bit
+    on any device (the sweep's padded evaluation sums the same quanta,
+    DESIGN.md §11).
+    """
+    units = units + jnp.where(jnp.isfinite(vote_cap),
+                              jnp.float32(problem.vote_units_approx),
+                              jnp.float32(problem.vote_units_exact))
+    return units * area_mod.AREA_QUANTUM_MM2 + problem.overhead_mm2
+
+
+def exact_matmul(a, b):
+    """f32 matmul of small integers, exact on every backend.
+
+    At the default precision XLA may narrow the operands (bf16 on a TPU;
+    it turned the 0/1 and ±1 matrices here into a pred x int8
+    convolution). On a TPU v5e such programs returned wrong accuracies for
+    a 256-chromosome pendigits forest[4] population and for the HAR tree
+    in 18-chromosome blocks; at HIGHEST both were right.
+    """
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def predict_votes(problem: SearchProblem, bits, t_sub, vote_cap=None):
@@ -145,21 +167,50 @@ def predict_votes(problem: SearchProblem, bits, t_sub, vote_cap=None):
     """
     x_p = quant.inputs_at_precision(problem.x_sel, bits)
     d = (x_p > t_sub[None, :]).astype(jnp.float32)
-    score = d @ problem.path.T.astype(jnp.float32)           # (B, L)
+    score = exact_matmul(d, problem.path.T.astype(jnp.float32))   # (B, L)
     target = (problem.path_len - problem.n_neg).astype(jnp.float32)
     sat = (score == target[None, :]).astype(jnp.float32)
     cls1h = jax.nn.one_hot(problem.leaf_class, problem.n_classes)
-    votes = sat @ cls1h                                      # (B, C)
+    votes = exact_matmul(sat, cls1h)                              # (B, C)
     if vote_cap is not None:
         # saturating (approximate) vote adder; +inf cap = exact no-op
         votes = jnp.minimum(votes, vote_cap)
     return jnp.argmax(votes, axis=1)
 
 
+def n_correct(problem: SearchProblem, pred):
+    """Exact f32 count of test samples ``pred`` classifies correctly."""
+    return jnp.sum((pred == problem.y).astype(jnp.float32), axis=-1)
+
+
+def accuracy(problem: SearchProblem, correct):
+    """Test accuracy from an exact correct count.
+
+    A multiplication by the constant reciprocal, not a division: XLA made
+    that multiplication of the division in the programs inspected, and
+    written out the result no longer depends on the rewrite, which can
+    differ from a true division in the last bit.
+    """
+    return correct * (1.0 / problem.y.shape[0])
+
+
+def objective_pair(problem: SearchProblem, correct, units, vote_cap):
+    """(accuracy loss vs exact, normalized area) from a design's exact
+    counts: correct test predictions and summed comparator area quanta.
+
+    Every tree backend ends in this one formula, which only adds and
+    multiplies by constants, so backends that agree on the counts agree
+    on the objectives bit for bit, on any device (see `accuracy`).
+    """
+    area = area_mm2(problem, units, vote_cap)
+    return (problem.exact_accuracy - accuracy(problem, correct),
+            area * (1.0 / problem.exact_area_mm2))
+
+
 def chromosome_accuracy(problem: SearchProblem, genes):
     bits, t_sub, vote_cap = decode_chromosome(problem, genes)
     pred = predict_votes(problem, bits, t_sub, vote_cap)
-    return jnp.mean((pred == problem.y).astype(jnp.float32))
+    return accuracy(problem, n_correct(problem, pred))
 
 
 def chromosome_area_mm2(problem: SearchProblem, genes):
@@ -167,8 +218,7 @@ def chromosome_area_mm2(problem: SearchProblem, genes):
     the vote-adder cell of the decoded mode (DESIGN.md §16)."""
     bits, t_sub, vote_cap = decode_chromosome(problem, genes)
     idx = problem.lut_offsets[bits] + t_sub
-    return (problem.area_lut[idx].sum() + problem.overhead_mm2
-            + vote_area_mm2(problem, vote_cap))
+    return area_mm2(problem, problem.area_lut_units[idx].sum(), vote_cap)
 
 
 def objectives(problem: SearchProblem, genes):
@@ -182,12 +232,10 @@ def objectives(problem: SearchProblem, genes):
     """
     bits, t_sub, vote_cap = decode_chromosome(problem, genes)
     pred = predict_votes(problem, bits, t_sub, vote_cap)
-    acc = jnp.mean((pred == problem.y).astype(jnp.float32))
     idx = problem.lut_offsets[bits] + t_sub
-    area = (problem.area_lut[idx].sum() + problem.overhead_mm2
-            + vote_area_mm2(problem, vote_cap))
-    return jnp.stack([problem.exact_accuracy - acc,
-                      area / problem.exact_area_mm2])
+    return jnp.stack(objective_pair(
+        problem, n_correct(problem, pred),
+        problem.area_lut_units[idx].sum(), vote_cap))
 
 
 # ---------------------------------------------------------------------------
@@ -214,23 +262,22 @@ def build_problem(ptrees, x_test: np.ndarray, y_test: np.ndarray,
     n_total = feature.shape[0]
     l_total = leaf_class.shape[0]
 
-    lut, offsets = area_mod.build_area_lut()
+    lut_units, offsets = area_mod.build_area_unit_lut()
     x8 = quantize_u8(x_test).astype(np.int32)
     overhead = area_mod.tree_overhead_mm2(n_total, l_total)
     # vote-adder cells, priced from the isolated netlist harness (§16);
     # both zero for K = 1 (no vote stage exists — the gene is inert)
-    vote_exact = area_mod.vote_adder_area_mm2(len(ptrees), int(n_classes),
-                                              approx=False)
-    vote_approx = area_mod.vote_adder_area_mm2(len(ptrees), int(n_classes),
-                                               approx=True)
+    vote_exact = area_mod.vote_adder_units(len(ptrees), int(n_classes),
+                                           approx=False)
+    vote_approx = area_mod.vote_adder_units(len(ptrees), int(n_classes),
+                                            approx=True)
 
-    # exact design: 8-bit, zero margin, exact vote adder (float64 LUT sum,
-    # like core.approx)
+    # exact design: 8-bit, zero margin, exact vote adder
     t8 = np.clip(np.floor(threshold.astype(np.float64) * 256.0), 0, 255)
     t8 = t8.astype(np.int64)
     exact_bits = np.full(n_total, quant.MAX_BITS, dtype=np.int64)
-    exact_area = float(lut[offsets[exact_bits] + t8].sum() + overhead
-                       + vote_exact)
+    exact_units = int(lut_units[offsets[exact_bits] + t8].sum()) + vote_exact
+    exact_area = exact_units * area_mod.AREA_QUANTUM_MM2 + overhead
 
     problem = SearchProblem(
         feature=jnp.asarray(feature),
@@ -243,7 +290,7 @@ def build_problem(ptrees, x_test: np.ndarray, y_test: np.ndarray,
         x8=jnp.asarray(x8),
         x_sel=jnp.asarray(x8[:, feature]),
         y=jnp.asarray(y_test.astype(np.int32)),
-        area_lut=jnp.asarray(lut),
+        area_lut_units=jnp.asarray(lut_units),
         lut_offsets=jnp.asarray(offsets),
         overhead_mm2=float(overhead),
         exact_area_mm2=exact_area,
@@ -253,8 +300,8 @@ def build_problem(ptrees, x_test: np.ndarray, y_test: np.ndarray,
         n_trees=len(ptrees),
         tree_comparators=tuple(pt.n_comparators for pt in ptrees),
         tree_leaves=tuple(pt.n_leaves for pt in ptrees),
-        vote_mm2_exact=float(vote_exact),
-        vote_mm2_approx=float(vote_approx),
+        vote_units_exact=vote_exact,
+        vote_units_approx=vote_approx,
     )
     exact_acc = float(chromosome_accuracy(
         problem, jnp.asarray(quant.exact_tree_genes(n_total))))

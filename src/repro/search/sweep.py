@@ -54,7 +54,7 @@ import numpy as np
 from repro.core import area as area_mod
 from repro.core import nsga2, quant
 from repro.search import engine as _engine
-from repro.search.problem import SearchProblem
+from repro.search.problem import SearchProblem, exact_matmul
 
 GRANULE = 8            # minimum padded extent per axis
 DEFAULT_MAX_BUCKETS = 6
@@ -228,10 +228,10 @@ def _padded_predict_decoded(pp: PaddedProblem, bits, t_sub, vote_cap):
     """(Bp,) voted class from an already-decoded chromosome."""
     x_p = quant.inputs_at_precision(pp.x_sel, bits)
     d = (x_p > t_sub[None, :]).astype(jnp.float32)
-    score = d @ pp.path.T.astype(jnp.float32)
+    score = exact_matmul(d, pp.path.T.astype(jnp.float32))
     target = (pp.path_len - pp.n_neg).astype(jnp.float32)
     sat = (score == target[None, :]).astype(jnp.float32)
-    votes = sat @ pp.leaf_onehot
+    votes = exact_matmul(sat, pp.leaf_onehot)
     # saturating (approximate) vote adder: +inf cap = exact f32 no-op
     votes = jnp.minimum(votes, vote_cap)
     return jnp.argmax(votes, axis=1)
@@ -253,9 +253,9 @@ def padded_predict(pp: PaddedProblem, genes):
 def padded_objectives(pp: PaddedProblem, genes):
     """(accuracy loss, normalized area) for one padded chromosome (3*Np+1,).
 
-    Matches `search.objectives` on the real slice up to float rounding (the
-    area term sums integer quanta instead of f32 mm^2 rows — that is what
-    buys vmap-order invariance); the *inertness* of pad genes is exact.
+    Matches `search.objectives` on the real slice up to float rounding
+    (both sum integer area quanta — that is what buys vmap-order
+    invariance); the *inertness* of pad genes is exact.
     One shared decode feeds both objectives (§12). The vote-adder term
     selects between the two integer unit counts (DESIGN.md §16), so the
     sum stays integer-valued in f32.

@@ -1,4 +1,5 @@
-"""Test-suite plumbing: a deterministic fallback `hypothesis` shim.
+"""Test-suite plumbing: a deterministic fallback `hypothesis` shim, and
+jax's persistent compilation cache put back after every test.
 
 The container image may lack the real `hypothesis` package and nothing can be
 pip-installed, so when the import fails we register a minimal stand-in that
@@ -14,6 +15,30 @@ import inspect
 import sys
 import types
 import zlib
+
+import pytest
+
+_CACHE_OPTIONS = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+                  "jax_persistent_cache_min_entry_size_bytes",
+                  "jax_persistent_cache_min_compile_time_secs")
+
+
+@pytest.fixture(autouse=True)
+def _restore_compilation_cache():
+    """The CLI entry points turn the persistent compilation cache on for the
+    whole process (`repro.runtime.compile_cache.configure`). Restore jax's
+    cache options after each test, so later tests in the same worker do not
+    write every compile to disk."""
+    import jax
+
+    saved = {k: getattr(jax.config, k) for k in _CACHE_OPTIONS}
+    yield
+    if any(getattr(jax.config, k) != v for k, v in saved.items()):
+        from jax.experimental.compilation_cache import compilation_cache as cc
+
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
 
 
 def _install_hypothesis_stub() -> None:

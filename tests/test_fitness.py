@@ -32,6 +32,16 @@ def forest_problem():
     return search.build_forest_problem(fr, ds.x_test, ds.y_test)
 
 
+@pytest.fixture(scope="module")
+def wide_forest_problem():
+    """pendigits forest[2]: N = L = 512 after padding, wide enough that the
+    derived leaf tile (128) is below the leaf axis."""
+    ds = load_dataset("pendigits")
+    fr = forest_mod.train_forest(ds.x_train, ds.y_train, ds.n_classes,
+                                 n_trees=2)
+    return search.build_forest_problem(fr, ds.x_test, ds.y_test)
+
+
 def _fit_operands(problem):
     return ops.prepare_fitness_operands(
         problem.x_sel, problem.y, problem.path, problem.path_len,
@@ -223,6 +233,20 @@ def test_decode_population_full_consistent(tree_problem):
         np.where(np.asarray(vote_w) > 0, np.float32(1.0), np.float32(np.inf)))
 
 
+def test_shift_scale_floor_equals_integer_shift():
+    """The kernels' ``floor(x8 * scale)`` is the reference's ``x8 >> s`` for
+    every master code and every shift the decode can produce: the scale is
+    the exact power of two, multiples of 2^s included."""
+    shifts = jnp.arange(quant.MASTER_BITS + 1, dtype=jnp.int32)
+    scale = np.asarray(jax.jit(quant.shift_scale)(shifts))
+    np.testing.assert_array_equal(
+        scale, 2.0 ** -np.arange(quant.MASTER_BITS + 1, dtype=np.float32))
+    x8 = np.arange(256, dtype=np.float32)[:, None]
+    np.testing.assert_array_equal(
+        np.floor(x8 * scale[None, :]).astype(np.int64),
+        np.arange(256)[:, None] >> np.arange(quant.MASTER_BITS + 1)[None, :])
+
+
 def test_fitness_errors_rejects_bad_blocking(tree_problem):
     from repro.kernels.fitness import fitness_errors as raw_kernel
     x_sel, path_t, target, cls1h, y_row = _fit_operands(tree_problem)
@@ -235,3 +259,77 @@ def test_fitness_errors_rejects_bad_blocking(tree_problem):
     with pytest.raises(ValueError, match="block_p"):
         raw_kernel(x_pad, scale, scale, path_t, target, cls1h, y_pad, vcap,
                    block_p=4, block_b=256, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# kernel repairs for the TPU compiler: int32 class iota, derived leaf tile
+# ---------------------------------------------------------------------------
+
+def test_fitness_kernel_argmax_first_max_on_ties():
+    """The in-kernel argmax (int32 iota cast to f32 + masked min) keeps
+    jnp.argmax's first-max rule: on tied votes the lowest class wins, with
+    and without the saturating vote cap."""
+    from repro.kernels.fitness import LANES, fitness_errors as raw_kernel
+
+    n = l = c = 128
+    n_b, n_pop, n_cls = 256, 8, 6
+    rng = np.random.default_rng(5)
+    leaf_class = np.arange(l) % n_cls
+    # leaf j fires iff comparator j fires; comparator j fires iff thr < 0
+    path_t = np.eye(n, l, dtype=np.float32)
+    target = np.ones((1, l), np.float32)
+    cls1h = np.zeros((l, c), np.float32)
+    cls1h[np.arange(l), leaf_class] = 1.0
+    # chromosome p fires 2 leaves of class a_p and 2 of class b_p (a tie),
+    # plus one leaf of a third class
+    fire = np.zeros((n_pop, n), bool)
+    for p in range(n_pop):
+        a, b, third = rng.choice(n_cls, 3, replace=False)
+        for cls, k in ((a, 2), (b, 2), (third, 1)):
+            fire[p, np.flatnonzero(leaf_class == cls)[:k]] = True
+    thr = np.where(fire, -1.0, 256.0).astype(np.float32)
+    cap = np.where(np.arange(n_pop) % 2, 1.0, np.inf).astype(np.float32)
+    votes = np.minimum(fire.astype(np.float32) @ cls1h[:n],
+                       cap[:, None])                       # (P, C)
+    pred = votes.argmax(axis=1)                            # first max
+    assert (np.sort(votes, axis=1)[:, -1] == np.sort(votes, axis=1)[:, -2]).all()
+    # class k labels k * 10 + 5 rows, so each count names one class
+    y = np.repeat(np.arange(n_cls), np.arange(n_cls) * 10 + 5)
+    y = np.concatenate([y, np.full(n_b - y.size, -1)]).astype(np.float32)
+    args = (jnp.zeros((n_b, n), jnp.float32), jnp.ones((n_pop, n)),
+            jnp.asarray(thr), jnp.asarray(path_t), jnp.asarray(target),
+            jnp.asarray(cls1h), jnp.asarray(y[None]),
+            jnp.broadcast_to(jnp.asarray(cap)[:, None], (n_pop, LANES)))
+    got = np.asarray(raw_kernel(*args, interpret=True))[:, 0]
+    np.testing.assert_array_equal(got, pred * 10.0 + 5.0)
+    want = ref.fitness_correct_counts(*args[:7], jnp.asarray(cap))
+    np.testing.assert_array_equal(got, np.asarray(want))
+
+
+def test_derived_block_l_below_leaf_axis_matches_whole_axis(
+        wide_forest_problem):
+    """``block_l=None`` derives a 128 leaf tile for a 512-leaf forest; the
+    tiled vote accumulation equals the whole-axis run, and the kernel
+    backend's objectives equal the reference backend's exactly."""
+    from repro.kernels import fitness as fit_mod
+
+    prob = wide_forest_problem
+    fit_ops = _fit_operands(prob)
+    _, path_t, _, cls1h, _ = fit_ops
+    n, l = path_t.shape
+    c = cls1h.shape[1]
+    assert l == 512
+    derived = fit_mod.pick_block_l(
+        l, lambda bl: fit_mod.vmem_bytes(n, bl, c, 256, 8))
+    assert derived == 128 < l
+    genes = jax.random.uniform(jax.random.PRNGKey(2), (8, prob.n_genes))
+    scale, thr, vote_cap = ops.decode_population(prob.threshold, genes)
+    tiled = np.asarray(ops.fitness_errors(fit_ops, scale, thr, vote_cap,
+                                          interpret=True))
+    whole = np.asarray(ops.fitness_errors(fit_ops, scale, thr, vote_cap,
+                                          block_l=l, interpret=True))
+    np.testing.assert_array_equal(tiled, whole)
+    f_ref = search.make_fitness(prob, "reference")
+    f_ker = search.make_fitness(prob, "kernel", interpret=True)
+    np.testing.assert_array_equal(np.asarray(f_ker(genes)),
+                                  np.asarray(f_ref(genes)))

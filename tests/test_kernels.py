@@ -84,6 +84,36 @@ def test_tree_infer_kernel_vs_ref_oracle_padded_ops(tree_setup):
                                    rtol=0, atol=0)
 
 
+def test_tree_infer_population_rows_match_single_rows(tree_setup):
+    """P > 1 population operands travel as (P, 1, N) with the leading axis
+    squeezed out of the block (the layout the TPU compiler accepts): every
+    row equals its own P = 1 launch and the jnp oracle, leaf tiling too."""
+    from repro.kernels.tree_infer import tree_infer_scores
+
+    ds, pt, x8 = tree_setup
+    sel, path_t, target, cls1h = ops.prepare_forest_operands(
+        [pt] * 5, ds.n_features)
+    assert path_t.shape[1] > 128   # block_l=128 really tiles the leaves
+    rng = np.random.default_rng(4)
+    n, p = sel.shape[1], 5
+    scale = jnp.asarray(np.exp2(-(8 - rng.integers(2, 9, (p, n))))
+                        .astype(np.float32))
+    thr = jnp.asarray(rng.integers(0, 256, (p, n)).astype(np.float32))
+    x8f = jnp.asarray(rng.integers(0, 256, (256, sel.shape[0]))
+                      .astype(np.float32))
+    want = ref.tree_infer_scores(x8f, sel, scale, thr, path_t, target, cls1h)
+    for block_l in (None, 128):
+        got = tree_infer_scores(x8f, sel, scale, thr, path_t, target, cls1h,
+                                block_b=128, block_l=block_l, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for i in range(p):
+            row = tree_infer_scores(x8f, sel, scale[i:i + 1], thr[i:i + 1],
+                                    path_t, target, cls1h, block_b=128,
+                                    block_l=block_l, interpret=True)
+            np.testing.assert_array_equal(np.asarray(row[0]),
+                                          np.asarray(got[i]))
+
+
 # ---------------------------------------------------------------------------
 # domination
 # ---------------------------------------------------------------------------

@@ -53,13 +53,12 @@ print("ISLANDS_OK", len(objs))
 # --- compressed cross-group psum --------------------------------------------
 from repro.optim import compress
 from functools import partial
-from jax.experimental.shard_map import shard_map
 
 mesh2 = Mesh(np.array(jax.devices()).reshape(2, 4), ("pod", "data"))
 x = jnp.arange(32.0).reshape(2, 16) / 7.0
 
-@partial(shard_map, mesh=mesh2, in_specs=(P("pod", None),), out_specs=P("pod", None),
-         check_rep=False)
+@partial(jax.shard_map, mesh=mesh2, in_specs=(P("pod", None),), out_specs=P("pod", None),
+         check_vma=False)
 def mean_pods(g):
     return compress.compressed_psum({"g": g}, "pod")["g"]
 

@@ -29,7 +29,12 @@ from repro.search.problem import decode_chromosome, predict_votes
 def seeds_tree():
     ds = load_dataset("seeds")
     tree = train_tree(ds.x_train, ds.y_train, ds.n_classes)
-    return ds, tree, to_parallel(tree)
+    pt = to_parallel(tree)
+    # compile the eager gene decode for this tree's shapes once, here, so a
+    # hypothesis deadline times each example and not jax's first compile
+    _decode(pt.threshold,
+            _legacy_genes(np.random.default_rng(0), pt.n_comparators))
+    return ds, tree, pt
 
 
 def _legacy_genes(rng, n_comparators: int) -> np.ndarray:

@@ -172,3 +172,74 @@ def test_int8_quantize_roundtrip_error():
     # error bounded by half a quantization step
     assert float(jnp.max(jnp.abs(back - g))) <= float(s) * 0.5 + 1e-9
     assert q.dtype == jnp.int8
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def cache_config(monkeypatch):
+    """No cache directory from the environment (conftest.py restores jax's
+    cache options after the test)."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+
+
+def test_compile_cache_env_var_wins(cache_config, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR is used as is: configure returns it, sets no
+    directory of its own, and the command-line directory is ignored."""
+    from repro.runtime import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    got = compile_cache.configure(str(tmp_path / "flag"))
+    assert got == str(tmp_path / "env")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "flag").exists()
+
+
+def test_compile_cache_default_is_fixed_inside_checkout(cache_config):
+    """Without the variable or a flag the cache goes to one fixed directory
+    inside the checkout, the same on every call."""
+    from pathlib import Path
+
+    from repro.runtime import compile_cache
+
+    first = compile_cache.configure()
+    second = compile_cache.configure()
+    checkout = Path(__file__).resolve().parents[1]
+    assert first == second == str(checkout / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+    assert jax.config.jax_enable_compilation_cache
+
+
+def test_compile_cache_flag_used_without_env_var(cache_config, tmp_path):
+    from repro.runtime import compile_cache
+
+    got = compile_cache.configure(str(tmp_path / "flag"))
+    assert got == str(tmp_path / "flag")
+    assert jax.config.jax_compilation_cache_dir == got
+    assert (tmp_path / "flag").is_dir()
+
+
+def test_compile_cache_written_to_env_dir(tmp_path):
+    """End to end, in a fresh interpreter: with JAX_COMPILATION_CACHE_DIR set
+    the compiled program lands in that directory."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    env_dir = tmp_path / "env"
+    src = Path(__file__).resolve().parents[1] / "src"
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.runtime import compile_cache\n"
+            "print(compile_cache.configure())\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(7)).block_until_ready()\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(env_dir),
+               JAX_PLATFORMS="cpu", PYTHONPATH=str(src))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120,
+                         check=True).stdout.split()
+    assert out == [str(env_dir), str(env_dir)]
+    assert any(env_dir.iterdir())

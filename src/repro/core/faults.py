@@ -32,6 +32,7 @@ shape regardless of how many sites a circuit has.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,9 @@ from repro.core.netlist import (
 # boolean value tensor (chunk, B, G) stays under this budget
 DEFAULT_CHUNK_BUDGET_BYTES = 64 << 20
 MAX_CHUNK = 256
+
+# the `call=` id of the profiler spans of one `FaultSimulator.run_masks` call
+_CALLS = itertools.count()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,6 +200,8 @@ class FaultSimulator:
         default); the final chunk pads with zero-fault lanes and crops, so
         at most one program compiles per (chunk, batch) shape.
         """
+        from repro.runtime import spans
+
         x8 = jnp.asarray(x8, jnp.int32)
         stuck_mask = np.asarray(stuck_mask, bool)
         stuck_val = np.asarray(stuck_val, bool)
@@ -212,15 +218,19 @@ class FaultSimulator:
             chunk = auto_chunk(self.circuit, int(x8.shape[0]))
         chunk = max(1, min(int(chunk), max(s, 1)))
         out = []
-        for lo in range(0, s, chunk):
-            m = stuck_mask[lo:lo + chunk]
-            v = stuck_val[lo:lo + chunk]
-            pad = chunk - m.shape[0]
-            if pad:
-                m = np.pad(m, ((0, pad), (0, 0)))
-                v = np.pad(v, ((0, pad), (0, 0)))
-            preds = self._vmapped(x8, jnp.asarray(m), jnp.asarray(v))
-            out.append(np.asarray(preds[:chunk - pad if pad else chunk]))
+        with spans.span("faults.run", call=next(_CALLS)):
+            spans.count("faults.lanes", s)
+            spans.count("faults.dispatches", -(-s // chunk))
+            for lo in range(0, s, chunk):
+                m = stuck_mask[lo:lo + chunk]
+                v = stuck_val[lo:lo + chunk]
+                pad = chunk - m.shape[0]
+                if pad:
+                    m = np.pad(m, ((0, pad), (0, 0)))
+                    v = np.pad(v, ((0, pad), (0, 0)))
+                preds = self._vmapped(x8, jnp.asarray(m), jnp.asarray(v))
+                with spans.span("faults.fetch"):  # waits for the device
+                    out.append(np.asarray(preds[:chunk - pad]))
         if not out:
             return np.zeros((0, int(x8.shape[0])), np.int32)
         return np.concatenate(out, axis=0)

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import spans
+
 
 def _flatten_with_paths(tree):
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
@@ -49,24 +51,27 @@ def save(ckpt_dir: str, step: int, tree, keep: int = 3,
     os.makedirs(ckpt_dir, exist_ok=True)
     leaves, _ = _flatten_with_paths(tree)
     arrays = {k: np.asarray(jax.device_get(v)) for k, v in leaves.items()}
-    manifest = {
-        "step": int(step),
-        "keys": sorted(arrays.keys()),
-        "shapes": {k: list(v.shape) for k, v in arrays.items()},
-        "dtypes": {k: str(v.dtype) for k, v in arrays.items()},
-        "shards": "full",
-        "meta": meta or {},
-    }
-    final = os.path.join(ckpt_dir, f"ckpt_{step:08d}")
-    with tempfile.TemporaryDirectory(dir=ckpt_dir) as tmp:
-        np.savez(os.path.join(tmp, "arrays.npz"), **arrays)
-        with open(os.path.join(tmp, "manifest.json"), "w") as f:
-            json.dump(manifest, f)
-        os.makedirs(final + ".tmp", exist_ok=True)
-        for name in ("arrays.npz", "manifest.json"):
-            os.replace(os.path.join(tmp, name), os.path.join(final + ".tmp", name))
-    os.replace(final + ".tmp", final)  # atomic publish
-    _gc(ckpt_dir, keep)
+    # the host's own work, once the state has reached it
+    with spans.span("checkpoint.write"):
+        manifest = {
+            "step": int(step),
+            "keys": sorted(arrays.keys()),
+            "shapes": {k: list(v.shape) for k, v in arrays.items()},
+            "dtypes": {k: str(v.dtype) for k, v in arrays.items()},
+            "shards": "full",
+            "meta": meta or {},
+        }
+        final = os.path.join(ckpt_dir, f"ckpt_{step:08d}")
+        with tempfile.TemporaryDirectory(dir=ckpt_dir) as tmp:
+            np.savez(os.path.join(tmp, "arrays.npz"), **arrays)
+            with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                json.dump(manifest, f)
+            os.makedirs(final + ".tmp", exist_ok=True)
+            for name in ("arrays.npz", "manifest.json"):
+                os.replace(os.path.join(tmp, name),
+                           os.path.join(final + ".tmp", name))
+        os.replace(final + ".tmp", final)  # atomic publish
+        _gc(ckpt_dir, keep)
     return final
 
 

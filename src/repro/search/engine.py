@@ -26,6 +26,7 @@ Features over the old loops:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import os
 import time
@@ -37,6 +38,9 @@ import numpy as np
 from repro.core import nsga2, quant
 from repro.search import backends as _backends
 from repro.search.problem import SearchProblem
+
+# the `campaign=` id of the profiler spans of one `run_search` call
+_CAMPAIGNS = itertools.count()
 
 
 @dataclasses.dataclass
@@ -380,7 +384,14 @@ def run_search(problem: SearchProblem, cfg: SearchConfig | None = None,
     if (cfg.emit_rtl or cfg.verify_rtl) and not cfg.out_dir:
         raise ValueError("emit_rtl/verify_rtl require out_dir")
 
-    t0 = time.time()
+    from repro.runtime import spans
+
+    with spans.span("search.run", campaign=next(_CAMPAIGNS)):
+        return _search(problem, cfg)
+
+
+def _search(problem: SearchProblem, cfg: SearchConfig) -> SearchResult:
+    t0 = time.perf_counter()
     if cfg.backend == "islands":
         state, n_evals, n_dispatches = _run_islands(problem, cfg)
     else:
@@ -394,7 +405,7 @@ def run_search(problem: SearchProblem, cfg: SearchConfig | None = None,
         mesh = make_search_mesh(cfg.mesh, axes=("pop",))
         state, n_evals, n_dispatches = _run_single(problem, cfg, fitness,
                                                    mesh=mesh)
-    wall_s = time.time() - t0
+    wall_s = time.perf_counter() - t0
 
     objs, genes = nsga2.pareto_front(jax.device_get(state.objs),
                                      jax.device_get(state.genes))
@@ -469,6 +480,7 @@ def write_pareto_artifact(problem: SearchProblem, result: SearchResult,
     dataset: optional dataset label recorded for the serving CLI.
     """
     from repro.core import netlist, rtl
+    from repro.runtime import spans
     from repro.search import artifact as _artifact
     from repro.search.problem import predict_votes, problem_ptrees
 
@@ -477,26 +489,35 @@ def write_pareto_artifact(problem: SearchProblem, result: SearchResult,
     if emit_rtl:
         os.makedirs(os.path.join(out_dir, "rtl"), exist_ok=True)
     kernel_predict = _make_kernel_predict(problem) if verify_rtl else None
+    spans.count("artifact.points", len(result.pareto_genes))
+    if spans.recording():   # a front keeps every copy of a rank-0 row
+        spans.count("artifact.distinct_points",
+                    len(np.unique(result.pareto_genes, axis=0)))
 
     points = []
     for i, (o, g) in enumerate(zip(result.pareto_objs, result.pareto_genes)):
-        g_j = jnp.asarray(g)
-        bits_j, margin, trunc_j, vote_j = quant.decode_tree_genes(g_j)
-        t_sub_j = quant.substitute(
-            quant.threshold_to_int(problem.threshold, bits_j), margin, bits_j)
-        bits = np.asarray(bits_j)
-        t_sub = np.asarray(t_sub_j)
-        trunc = np.asarray(trunc_j)
-        vote_adder = "approx" if int(vote_j) else "exact"
-        circuit = netlist.build_circuit(ptrees, bits, t_sub,
-                                        problem.n_classes, trunc=trunc,
-                                        vote_adder=vote_adder)
+        with spans.span("artifact.decode"):
+            g_j = jnp.asarray(g)
+            bits_j, margin, trunc_j, vote_j = quant.decode_tree_genes(g_j)
+            t_sub_j = quant.substitute(
+                quant.threshold_to_int(problem.threshold, bits_j), margin,
+                bits_j)
+            bits = np.asarray(bits_j)
+            t_sub = np.asarray(t_sub_j)
+            trunc = np.asarray(trunc_j)
+            vote_adder = "approx" if int(vote_j) else "exact"
+        with spans.span("artifact.netlist"):
+            circuit = netlist.build_circuit(ptrees, bits, t_sub,
+                                            problem.n_classes, trunc=trunc,
+                                            vote_adder=vote_adder)
+            area_netlist = round(netlist.netlist_area_mm2(circuit), 4)
+            gates = netlist.gate_counts(circuit)
         point = {
             "acc_loss": float(o[0]),
             "norm_area": float(o[1]),
             "area_mm2": float(o[1] * problem.exact_area_mm2),
-            "area_netlist_mm2": round(netlist.netlist_area_mm2(circuit), 4),
-            "netlist_gates": netlist.gate_counts(circuit),
+            "area_netlist_mm2": area_netlist,
+            "netlist_gates": gates,
             "bits": bits.tolist(),
             "margin": np.asarray(margin).tolist(),
             "t_int": t_sub.tolist(),

@@ -455,6 +455,19 @@ def netlist_area_ratios(points) -> list[float]:
             if p["area_mm2"] > 0]
 
 
+@jax.jit
+def decode_front(threshold, genes):
+    """(R, 3N+1) genes -> int32 (bits, margin, t_sub, trunc, vote) of every
+    row: the per-comparator design of DESIGN.md §16, pre-truncation, with
+    the substituted integer thresholds — the arithmetic of
+    `kops.decode_population_full` before truncation is folded in.
+    `threshold` (N,) is an argument, so the compile is keyed on shapes."""
+    bits, margin, trunc, vote = quant.decode_tree_genes(genes)
+    t_sub = quant.substitute(quant.threshold_to_int(threshold, bits), margin,
+                             bits)
+    return bits, margin, t_sub, trunc, vote
+
+
 def write_pareto_artifact(problem: SearchProblem, result: SearchResult,
                           out_dir: str, *, emit_rtl: bool = False,
                           verify_rtl: bool = False,
@@ -494,18 +507,19 @@ def write_pareto_artifact(problem: SearchProblem, result: SearchResult,
         spans.count("artifact.distinct_points",
                     len(np.unique(result.pareto_genes, axis=0)))
 
+    # one compiled decode of the whole front, padded to the population's
+    # row count so every campaign of one problem shares one program
+    genes = np.asarray(result.pareto_genes, np.float32)
+    n_rows = max(len(genes), result.state.genes.shape[0])
+    block = np.zeros((n_rows, genes.shape[1]), np.float32)
+    block[:len(genes)] = genes
+    spans.count("artifact.decode_rows", n_rows)
+    with spans.span("artifact.decode"):
+        front = jax.device_get(decode_front(problem.threshold, block))
     points = []
     for i, (o, g) in enumerate(zip(result.pareto_objs, result.pareto_genes)):
-        with spans.span("artifact.decode"):
-            g_j = jnp.asarray(g)
-            bits_j, margin, trunc_j, vote_j = quant.decode_tree_genes(g_j)
-            t_sub_j = quant.substitute(
-                quant.threshold_to_int(problem.threshold, bits_j), margin,
-                bits_j)
-            bits = np.asarray(bits_j)
-            t_sub = np.asarray(t_sub_j)
-            trunc = np.asarray(trunc_j)
-            vote_adder = "approx" if int(vote_j) else "exact"
+        bits, margin, t_sub, trunc, vote = (a[i] for a in front)
+        vote_adder = "approx" if vote else "exact"
         with spans.span("artifact.netlist"):
             circuit = netlist.build_circuit(ptrees, bits, t_sub,
                                             problem.n_classes, trunc=trunc,
@@ -519,7 +533,7 @@ def write_pareto_artifact(problem: SearchProblem, result: SearchResult,
             "area_netlist_mm2": area_netlist,
             "netlist_gates": gates,
             "bits": bits.tolist(),
-            "margin": np.asarray(margin).tolist(),
+            "margin": margin.tolist(),
             "t_int": t_sub.tolist(),
             "trunc": trunc.tolist(),
             "vote_adder": vote_adder,
@@ -533,13 +547,12 @@ def write_pareto_artifact(problem: SearchProblem, result: SearchResult,
                 f.write(verilog)
             point["rtl"] = rel
         if verify_rtl:
-            vote_cap = jnp.where(vote_j > 0, jnp.float32(1.0),
-                                 jnp.float32(jnp.inf))
+            vote_cap = jnp.float32(1.0 if vote > 0 else jnp.inf)
             sim = np.asarray(netlist.simulate(circuit, problem.x8))
             ref = np.asarray(predict_votes(
-                problem, bits_j - trunc_j, jnp.right_shift(t_sub_j, trunc_j),
-                vote_cap))
-            ker = np.asarray(kernel_predict(g_j))
+                problem, jnp.asarray(bits - trunc),
+                jnp.asarray(t_sub >> trunc), vote_cap))
+            ker = np.asarray(kernel_predict(jnp.asarray(g)))
             if not (np.array_equal(sim, ref) and np.array_equal(sim, ker)):
                 n_ref = int((sim != ref).sum())
                 n_ker = int((sim != ker).sum())

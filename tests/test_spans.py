@@ -92,7 +92,9 @@ def test_search_campaign_counts_exactly(traced_search):
     assert saves == [2, 4, 5]
     assert totals["checkpoint.write"]["calls"] == len(saves)
     assert totals["artifact.points"] == n_points
-    assert totals["artifact.decode"]["calls"] == n_points
+    # the whole front decodes in one call, padded to the population (8)
+    assert totals["artifact.decode"]["calls"] == 1
+    assert totals["artifact.decode_rows"] == 8
     assert totals["artifact.netlist"]["calls"] == n_points
     genes = np.array([p["genes"] for p in payload["pareto"]])
     assert totals["artifact.distinct_points"] == len(np.unique(genes, axis=0))
@@ -190,5 +192,6 @@ def test_profile_holds_program_events_with_the_campaign(traced_search):
     assert len(campaign) == 1
     # every span under the campaign carries its id
     assert all(s.get("campaign") in campaign for _, s in events)
-    assert sum(n == "repro:artifact.decode" for n, _ in events) == len(
+    assert sum(n == "repro:artifact.decode" for n, _ in events) == 1
+    assert sum(n == "repro:artifact.netlist" for n, _ in events) == len(
         payload["pareto"])

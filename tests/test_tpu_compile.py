@@ -22,8 +22,10 @@ SHAPES = {
     "har_tree": (588, 589, 6, 561, 3090),
     "pendigits_forest4": (872, 876, 10, 16, 3298),
     "har_forest4": (1800, 1804, 6, 561, 3090),
+    "pendigits_tree": (225, 226, 10, 16, 3298),
 }
 POP = 256           # chromosomes per fitness call in a pop_size=256 search
+SEARCH_POP = {"pendigits_tree": 4096}   # the benchmark's large population
 MLP_HIDDEN = 16     # printed-MLP default hidden width
 MLP_POP = 64        # default population
 
@@ -75,14 +77,15 @@ def _tree_operands(spec, s, *, with_sel: bool):
 
 
 @pytest.mark.parametrize("spec", ["seeds_tree", "har_tree",
-                                  "pendigits_forest4"])
+                                  "pendigits_forest4", "pendigits_tree"])
 def test_fitness_kernel_compiles_default_block_l(one_chip, spec):
     n, _, _, _, b = SHAPES[spec]
+    p = SEARCH_POP.get(spec, POP)
     s = functools.partial(_sds, one_chip)
     fit_ops = _tree_operands(spec, s, with_sel=False) + (s((1, b)),)
     _compile_has_kernel(
         lambda o, sc, t, v: ops.fitness_errors(o, sc, t, v, interpret=False),
-        fit_ops, s((POP, n)), s((POP, n)), s((POP,)))
+        fit_ops, s((p, n)), s((p, n)), s((p,)))
 
 
 @pytest.mark.parametrize("bucket", [8, 64, 1024])
@@ -109,11 +112,13 @@ def test_tree_infer_predict_compiles_for_a_population(one_chip):
         s((b, f), jnp.int32), pt_ops, s((p, n)), s((p, n)), s((p,)))
 
 
-def test_domination_block_compiles_1024(one_chip):
+@pytest.mark.parametrize("rows", [1024, 8192])
+def test_domination_block_compiles_1024(one_chip, rows):
+    """1,024 rows, and the 8,192-row pool of a pop-4,096 search."""
     s = functools.partial(_sds, one_chip)
     _compile_has_kernel(
         lambda a, b: ops.domination_block(a, b, interpret=False),
-        s((1024, 2)), s((1024, 2)))
+        s((rows, 2)), s((rows, 2)))
 
 
 def test_qmatmul_compiles_at_mlp_route_shape(one_chip):

@@ -199,6 +199,14 @@ class FaultSimulator:
         Lanes run in chunks of `chunk` (auto-sized to the memory budget by
         default); the final chunk pads with zero-fault lanes and crops, so
         at most one program compiles per (chunk, batch) shape.
+
+        The masks go to the device once, every chunk is dispatched without
+        waiting for the one before, and the predictions come back in one
+        copy at the end. So a call holds all its lanes on the device until
+        it returns: S x G x 2 bytes of masks and S x B x 4 bytes of
+        predictions, padding included (the exhaustive HAR campaign,
+        5,408 lanes of a 2,706-gate circuit over 3,090 samples: 29 MB and
+        67 MB), beside one chunk's (chunk, B, G) bool table.
         """
         from repro.runtime import spans
 
@@ -217,23 +225,21 @@ class FaultSimulator:
         if chunk is None:
             chunk = auto_chunk(self.circuit, int(x8.shape[0]))
         chunk = max(1, min(int(chunk), max(s, 1)))
-        out = []
+        n_chunks = -(-s // chunk)
         with spans.span("faults.run", call=next(_CALLS)):
             spans.count("faults.lanes", s)
-            spans.count("faults.dispatches", -(-s // chunk))
-            for lo in range(0, s, chunk):
-                m = stuck_mask[lo:lo + chunk]
-                v = stuck_val[lo:lo + chunk]
-                pad = chunk - m.shape[0]
-                if pad:
-                    m = np.pad(m, ((0, pad), (0, 0)))
-                    v = np.pad(v, ((0, pad), (0, 0)))
-                preds = self._vmapped(x8, jnp.asarray(m), jnp.asarray(v))
-                with spans.span("faults.fetch"):  # waits for the device
-                    out.append(np.asarray(preds[:chunk - pad]))
-        if not out:
-            return np.zeros((0, int(x8.shape[0])), np.int32)
-        return np.concatenate(out, axis=0)
+            spans.count("faults.dispatches", n_chunks)
+            if not n_chunks:
+                return np.zeros((0, int(x8.shape[0])), np.int32)
+            pad = ((0, n_chunks * chunk - s), (0, 0))
+            masks, vals = jax.device_put(
+                (np.pad(stuck_mask, pad).reshape(n_chunks, chunk, -1),
+                 np.pad(stuck_val, pad).reshape(n_chunks, chunk, -1)))
+            # iterating a device array splits it in one program of its own
+            preds = [self._vmapped(x8, m, v) for m, v in zip(masks, vals)]
+            with spans.span("faults.fetch"):  # the call's one wait
+                preds = jax.device_get(preds)
+        return np.concatenate(preds)[:s]
 
     def run_sites(self, x8, gates, values,
                   chunk: int | None = None) -> np.ndarray:

@@ -175,16 +175,50 @@ def test_run_masks_shape_validation():
         sim.run_masks(x8, good, np.zeros((3, circuit.n_gates), bool))
 
 
-def test_chunking_is_invisible():
+def _multi_hot_lanes(circuit, n_lanes, seed=5):
+    """(n_lanes, G) Monte-Carlo-shaped masks: 1–4 random sites a lane."""
+    sites = np.array([s.gate for s in faults.enumerate_fault_sites(circuit)])
+    rng = np.random.default_rng(seed)
+    mask = np.zeros((n_lanes, circuit.n_gates), bool)
+    for lane in range(n_lanes):
+        mask[lane, rng.choice(sites, size=rng.integers(1, 5),
+                              replace=False)] = True
+    return mask, mask & rng.integers(0, 2, mask.shape).astype(bool)
+
+
+@pytest.mark.parametrize("shape", ["single_hot", "multi_hot"])
+def test_chunking_is_invisible(shape):
     """Any chunk size — 1, prime, larger than the lane count — returns the
-    identical campaign (padding lanes are cropped, never leaked)."""
+    identical campaign (padding lanes are cropped, never leaked), with all
+    of a call's chunks in flight before its one copy back."""
     circuit, x8, _ = _tree_circuit()
     sim = faults.FaultSimulator(circuit)
-    gates, values = faults.single_fault_lanes(circuit)
-    ref = sim.run_sites(x8, gates, values, chunk=len(gates))
-    for chunk in (1, 13, len(gates) + 100):
+    if shape == "single_hot":
+        mask, val = faults.site_masks(
+            circuit.n_gates, *faults.single_fault_lanes(circuit))
+    else:
+        mask, val = _multi_hot_lanes(circuit, 60)
+    ref = sim.run_masks(x8, mask, val, chunk=len(mask))
+    for chunk in (1, 13, len(mask) + 100):
         np.testing.assert_array_equal(
-            ref, sim.run_sites(x8, gates, values, chunk=chunk))
+            ref, sim.run_masks(x8, mask, val, chunk=chunk))
+
+
+def test_ragged_tail_is_cropped_and_matches_serial_oracle():
+    """37 lanes at chunk 8: five chunks, the last padded with three
+    zero-fault lanes that never reach the result; every lane is the serial
+    oracle's."""
+    circuit, x8, _ = _tree_circuit()
+    sim = faults.FaultSimulator(circuit)
+    mask, val = _multi_hot_lanes(circuit, 37, seed=9)
+    preds = sim.run_masks(x8, mask, val, chunk=8)
+    assert preds.shape == (37, x8.shape[0])
+    for i in range(37):
+        stuck = np.flatnonzero(mask[i])
+        np.testing.assert_array_equal(
+            preds[i], faults.simulate_faulty_serial(
+                circuit, x8, zip(stuck, val[i, stuck])),
+            err_msg=f"lane {i}")
 
 
 # --- campaign metrics ------------------------------------------------------

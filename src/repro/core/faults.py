@@ -15,7 +15,8 @@ This module turns any `core.netlist.Circuit` into a fault-injection target:
     per-level mask (`where(stuck_mask[level_gates], stuck_val, computed)`),
     and `jax.vmap` lifts it over a whole chunk of fault lanes at once.
     A lane with an empty mask is therefore *bit-identical* to
-    `netlist.simulate` — the zero-fault invariant `check_bench` pins at
+    `netlist.simulate` — the zero-fault invariant
+    `tests/test_faults.py::test_zero_fault_bit_identical_to_simulate` pins at
     exactly 0 mismatches.
   - `simulate_faulty_serial` is the deliberately naive oracle: a pure
     Python/numpy loop over gates in topological order with the fault

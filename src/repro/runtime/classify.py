@@ -24,8 +24,9 @@ point via `search.load_pareto_artifact`, or directly from decoded
     of an LM server's prefill/insert/generate): `featurize` quantizes float
     features to the master 8-bit grid, `batch` pads request codes to bucket
     shape, and the classify step runs the fused inference kernel.
-    `benchmarks/serve_bench.py` times each stage separately and records
-    `serving` rows in BENCH_search.json.
+    `tests/test_serve_classifier.py::test_steady_state_serving_allocates_nothing`
+    pins the zero-realloc invariant, and `::test_bucket_and_pingpong_invariance`
+    pins zero retraces after warm-up.
 
 Every fast path is pinned bit-exact against the gate-level netlist
 simulator (`core/netlist.py`) — the oracle triangle (served == tensor
@@ -436,6 +437,6 @@ class ClassifyServer:
 
     def compile_count(self) -> int:
         """Total compiled step specializations across buckets — steady-state
-        serving must not grow this (`serve_bench` records the delta as
-        `compiles_after_warmup`, floor-checked at 0)."""
+        serving must not grow this (pinned at 0 new compiles after warm-up by
+        `tests/test_serve_classifier.py::test_bucket_and_pingpong_invariance`)."""
         return sum(fn._cache_size() for fn in self._steps.values())

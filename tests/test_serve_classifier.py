@@ -11,6 +11,8 @@ backends.  Also covers:
     `codes & 0xFF` must match the netlist's bits-0..7 reads);
   - bucket invariance: padding rows and >= 3 consecutive ping-pong steps
     never change real-row predictions;
+  - steady state: once a bucket's two slots are warm, steps allocate no
+    new device arrays and compile nothing new;
   - `pareto.json` loader round-trips: re-serving a point reproduces its
     recorded accuracy; missing/unknown keys raise `ValueError` (never a
     bare `KeyError`);
@@ -19,10 +21,12 @@ backends.  Also covers:
 from __future__ import annotations
 
 import copy
+import gc
 import importlib
 import json
 import sys
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -226,6 +230,25 @@ def test_bucket_and_pingpong_invariance(searched, backend):
         np.testing.assert_array_equal(server.classify(codes[:5]), alone)
     assert server.compile_count() == compiles   # no steady-state retrace
     assert server.stats.steps_per_bucket[8] >= 5
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_steady_state_serving_allocates_nothing(searched, backend):
+    """Once both ping-pong slots of a bucket are warm, further steps
+    recycle the resident buffers: the live device-array count stays flat."""
+    _, artifact, _, ds = searched[("seeds", 3)]
+    server = ClassifyServer.from_artifact(
+        artifact, point=artifact.best_under_loss(1.0), backend=backend)
+    codes = server.featurize(np.asarray(ds.x_test)[:10]).astype(np.int32)
+    padded = [server.batch(codes[:5])[0][0], server.batch(codes[5:])[0][0]]
+    for i in range(4):                       # warm both slots of bucket 8
+        np.asarray(server.step(padded[i % 2]))
+    gc.collect()
+    live = len(jax.live_arrays())
+    for i in range(8):
+        np.asarray(server.step(padded[i % 2]))
+    gc.collect()
+    assert len(jax.live_arrays()) == live
 
 
 def test_manual_padding_is_inert(searched):

@@ -86,10 +86,10 @@ def main(argv=None) -> int:
             print(f"ERROR: {flag} ({CLI_SOURCE}) is undocumented in {doc}",
                   file=sys.stderr)
 
-    # flags documented for OTHER CLIs (benchmarks.run, tools/check_*.py,
-    # chip_smoke.py)
-    other_clis = {"--quick", "--smoke", "--fitness-only", "--strict",
-                  "--path", "--xla", "--four-chips"}
+    # flags documented for OTHER CLIs (benchmarks.run, bench/run.py,
+    # tools/check_*.py, chip_smoke.py)
+    other_clis = {"--quick", "--workload", "--strict", "--xla",
+                  "--four-chips"}
     stale = sorted(documented - flags - other_clis)
     for flag in stale:
         level = "ERROR" if args.strict else "WARN"
